@@ -40,6 +40,9 @@ class ServiceConfig:
     query_window_s: float = 120.0
     shed: bool = True
     seed: int = 0
+    # False serves the published model config at full vocab
+    # (ScenarioSpec.reduced)
+    reduced: bool = True
     # closed-loop serving knobs: bounded stage channels (backpressure) and
     # the per-stage micro-batching window (collect batch_size or wait)
     max_queue: int = 512
@@ -78,7 +81,8 @@ class ServiceConfig:
             name=self.arch_id, arch_id=self.arch_id, pipeline="rerank",
             shed=self.shed, batch_size=self.batch_size,
             batch_buckets=self.rerank_buckets,
-            cand_buckets=self.cand_buckets, seed=self.seed)
+            cand_buckets=self.cand_buckets, seed=self.seed,
+            reduced=self.reduced)
 
     def make_substrate(self) -> ServingSubstrate:
         kw = dict(

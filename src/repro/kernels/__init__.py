@@ -1,28 +1,25 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Shared kernel-dispatch policy.
+"""Shared kernel-dispatch policy: ONE device decision.
 
-Every ``ops.py`` wrapper takes ``interpret: bool | None = None`` and resolves
-``None`` through :func:`default_interpret` at trace time — the Pallas
-interpreter only when no TPU backend is attached (CPU containers, CI), the
-compiled kernel on real hardware. ``REPRO_PALLAS_INTERPRET=0/1`` overrides
-both ways (e.g. force-interpret on TPU while debugging a kernel).
+:func:`platform` is the only place the kernels ask which hardware they
+run on. Every ``ops.py`` wrapper takes ``interpret: bool | None = None``
+and resolves ``None`` through :func:`default_interpret` at trace time —
+the Pallas interpreter only on the CPU, the compiled kernel everywhere
+else — and ``rerank_score``'s auto impl reads the same function. Tests
+that want the interpreter pass ``interpret=True`` explicitly.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
-_TRUTHY = ("1", "true", "True", "yes")
 
-
-def tpu_present() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+def platform() -> str:
+    """Platform of the default device (``"cpu"``, ``"tpu"``, ...). Any
+    backend-initialisation error propagates: a broken TPU runtime must
+    fail loudly, not quietly turn into a CPU decision."""
+    return jax.devices()[0].platform
 
 
 def pad_axis(x, mult: int, axis: int):
@@ -38,16 +35,11 @@ def pad_axis(x, mult: int, axis: int):
 
 
 def default_interpret() -> bool:
-    """True ⇒ run Pallas kernels in interpreter mode.
+    """True ⇒ run Pallas kernels in interpreter mode (CPU only).
 
     Resolution happens when an op is traced; the decision is baked into that
-    trace (it is a static argument), so flipping the env var mid-process only
-    affects shapes not yet compiled.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env in _TRUTHY
-    return not tpu_present()
+    trace (it is a static argument)."""
+    return platform() == "cpu"
 
 
 def resolve_interpret(interpret) -> bool:

@@ -6,8 +6,8 @@ dispatches to one of three implementations of the SAME fused algorithm
     when ``interpret`` resolves True — parity/debug only, it is slow);
   * ``impl="xla"``    — the fused algorithm as blocked jnp: identical sums,
     no (C,T,4D) materialization; the serving default off-TPU;
-  * ``impl=None``     — auto: "pallas" when a TPU backend is attached,
-    "xla" otherwise (see ``repro.kernels.default_interpret``).
+  * ``impl=None``     — auto: "pallas" when ``repro.kernels.platform()``
+    is "tpu", "xla" otherwise.
 
 Callers hand the history ALREADY compacted/bucketed (serve/bucketing.py):
 masked rows are exact no-ops, so scoring ``bucket(T_valid)`` rows is
@@ -20,7 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import pad_axis, resolve_interpret, tpu_present
+from repro import kernels
+from repro.kernels import pad_axis, resolve_interpret
 from repro.kernels.rerank_score.kernel import rerank_score_pallas
 
 
@@ -45,7 +46,6 @@ def _fused_block_xla(hist, mask, tgt, uo, io,
     return (s @ m3 + mb3)[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "impl", "interpret"))
 def rerank_score(hist, mask, target, user_other, item_other,
                  attn_mlp, score_mlp, block_c: int = 128,
                  impl: str | None = None, interpret: bool | None = None):
@@ -63,14 +63,23 @@ def rerank_score(hist, mask, target, user_other, item_other,
     AT MOST ``block_c`` and never pads C — a 16-candidate bucket costs 16
     rows of work, not 128.
     """
+    # the device decision is resolved here, outside the jit, so it is part
+    # of the jit cache key and never replayed from a trace made under
+    # another decision
+    if impl is None:
+        impl = "pallas" if kernels.platform() == "tpu" else "xla"
+    return _rerank_score(hist, mask, target, user_other, item_other,
+                         attn_mlp, score_mlp, block_c=block_c, impl=impl,
+                         interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("block_c", "impl", "interpret"))
+def _rerank_score(hist, mask, target, user_other, item_other,
+                  attn_mlp, score_mlp, block_c: int, impl: str,
+                  interpret: bool):
     assert len(attn_mlp) == 3 and len(score_mlp) == 3, \
         "fused path expects 2-hidden-layer towers (got " \
         f"{len(attn_mlp)}/{len(score_mlp)} layers)"
-    if impl is None:
-        # keyed on the hardware, NOT on default_interpret(): forcing
-        # REPRO_PALLAS_INTERPRET=1 on a TPU must debug the Pallas kernel
-        # (interpreted), not silently reroute to the XLA impl
-        impl = "pallas" if tpu_present() else "xla"
     C = target.shape[0]
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     hist_p = pad_axis(f32(hist), 8, 0)
@@ -83,7 +92,7 @@ def rerank_score(hist, mask, target, user_other, item_other,
         io_p = pad_axis(f32(item_other), block_c, 0)
         out = rerank_score_pallas(
             hist_p, mask_p, target_p, uo, io_p, *weights,
-            block_c=block_c, interpret=resolve_interpret(interpret))[:C]
+            block_c=block_c, interpret=interpret)[:C]
     elif impl == "xla":
         target_p, io_p = f32(target), f32(item_other)
         blocks = [

@@ -3,27 +3,19 @@ module never touches jax device state (required by the dry-run contract)."""
 from __future__ import annotations
 
 import jax
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    """jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    so on older jax we simply omit the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (elastic restarts, tests). shape/axes like above."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_types_kw(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh():

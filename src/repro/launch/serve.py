@@ -2,6 +2,8 @@
 or the recsys JiZHI service (examples/quickstart path), from one CLI.
 
   PYTHONPATH=src python -m repro.launch.serve --mode recsys --requests 96
+  PYTHONPATH=src python -m repro.launch.serve --mode recsys --no-reduced \
+      --arch din          # published widths at full vocab: one 16 GiB chip
   PYTHONPATH=src python -m repro.launch.serve --mode lm --arch smollm-135m \
       --requests 6 --reduced
 
@@ -66,6 +68,7 @@ def serve_recsys(args):
     from repro.core.service import InferenceService, ServiceConfig
     cfg = ServiceConfig(
         arch_id=args.arch if args.arch != "smollm-135m" else "din",
+        reduced=args.reduced,
         # crash safety (DESIGN.md §9): --snapshot-dir enables periodic
         # durable snapshots + SIGTERM final-snapshot; --recover boots from
         # the newest valid snapshot and replays the delta log
@@ -171,7 +174,10 @@ def main():
     ap.add_argument("--mode", choices=["recsys", "lm"], default="recsys")
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--requests", type=int, default=32)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the CPU-sized config; --no-reduced serves "
+                         "the published widths at full vocab")
     ap.add_argument("--snapshot-dir", default=None,
                     help="recsys: durable cube snapshots here (enables "
                          "periodic snapshot + SIGTERM final snapshot)")
@@ -194,6 +200,8 @@ def main():
                     help="recsys: export tail-sampled request traces as "
                          "Chrome trace-event JSON to this file")
     args = ap.parse_args()
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "recsys":
         serve_recsys(args)
     else:

@@ -210,7 +210,7 @@ def moe_apply(p: dict, x: jax.Array, cfg, act: str = "silu"):
     w_spec_1 = P("model", None, "data" if f_sharded else None)
     w_spec_2 = P("model", "data" if f_sharded else None, None)
     tok_spec = P(batch_axes, None) if tok_sharded else P(None, None)
-    fn = runtime.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec, P(None, None), w_spec_1, w_spec_1, w_spec_2),
         out_specs=(tok_spec, P()),

@@ -9,7 +9,9 @@ tests and CPU examples).
 from __future__ import annotations
 
 import contextlib
-from typing import Optional, Sequence
+import os
+from pathlib import Path
+from typing import Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -58,18 +60,6 @@ def data_axis_size() -> int:
     return n
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map moved out of jax.experimental after 0.4.x (and renamed
-    check_rep → check_vma); dispatch to whichever this jax provides."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
 def shard(x, *spec):
     """with_sharding_constraint that no-ops without a mesh."""
     mesh = current_mesh()
@@ -91,3 +81,22 @@ def pad_to_multiple(n: int, m: int) -> int:
 
 def divides(n: int, name: str) -> bool:
     return n % axis_size(name) == 0
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a serving process
+    and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own choice and is
+    left alone. Otherwise the cache lives at ``<repo root>/.jax_cache``: a
+    fixed path, because the path is part of the cache key, so a per-run
+    directory would never hit. The thresholds drop to zero so the many
+    small bucketed programs (one per batch/candidate/history bucket,
+    each well under JAX's default 1 s minimum) are cached too."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(Path(__file__).resolve().parents[2]
+                              / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir

@@ -166,6 +166,11 @@ class TracedJit:
             self.signatures.add(sig)
         return self._jit(*args, **kwargs)
 
+    def lower(self, *args, **kwargs):
+        """The jitted program for these arguments (shapes or arrays),
+        uncompiled — ``.compile().as_text()`` shows what the device runs."""
+        return self._jit.lower(*args, **kwargs)
+
     @property
     def n_traces(self) -> int:
         if not self._count_sigs:

@@ -93,6 +93,9 @@ class ScenarioSpec:
     cand_buckets: Optional[tuple] = None   # candidate count C
     hist_bucket_step: int = 8              # history length T menu step
     seed: int = 0
+    # True serves ``arch.reduced(arch.config)`` (CPU-sized widths and
+    # vocabularies); False serves the published config at full vocab
+    reduced: bool = True
 
     def __post_init__(self):
         if self.pipeline not in ("rerank", "retrieval"):
@@ -267,6 +270,7 @@ class ServingSubstrate:
         self.recovering = False
         self.recovery_target = -1
         self.last_replay_s = 0.0     # duration of the last delta-log replay
+        self.table_load_s = 0.0      # host seconds spent loading group tables
         self._rng = np.random.default_rng(seed)
         self._groups: dict[tuple[str, int], int] = {}
         self.bucket_items: dict[int, BoundedReverseMap] = {}
@@ -290,8 +294,10 @@ class ServingSubstrate:
             return self._groups[key]
         g = len(self._groups)
         self._groups[key] = g
+        t0 = time.perf_counter()
         self.cube.load_table(g, self._rng.normal(
             0, 0.01, (int(vocab), self.tail_dim)).astype(np.float32))
+        self.table_load_s += time.perf_counter() - t0
         mem, disk = capacity_from_ratio(int(vocab) * self.tail_dim,
                                         self.cube_cache_ratio)
         self.cube_cache.mem.capacity += mem
@@ -497,10 +503,16 @@ class ScenarioRuntime:
         self.spec = spec
         self.substrate = substrate
         arch = registry.get(spec.arch_id)
-        self.model_cfg = arch.reduced(arch.config)
+        self.model_cfg = (arch.reduced(arch.config) if spec.reduced
+                          else arch.config)
         from repro.launch.specs import REC_MODULES
         self.mod = REC_MODULES[self.model_cfg.model]
-        params = self.mod.init(jax.random.PRNGKey(spec.seed), self.model_cfg)
+        # one jitted init: eagerly, each full-vocab table's normal draw and
+        # its scaled copy are live at once next to the tables already made
+        # (~18 GiB for DIN on a 16 GiB chip); jitted, XLA writes each
+        # table once (~12.9 GiB peak)
+        params = jax.jit(self.mod.init, static_argnums=1)(
+            jax.random.PRNGKey(spec.seed), self.model_cfg)
         self.buffer = DoubleBuffer(Generation(0, params))
         # any scenario's generation swap bumps the shared query cache's
         # model version (over-invalidation across scenarios: safe)

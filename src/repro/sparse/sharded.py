@@ -63,7 +63,7 @@ def sharded_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
     id_spec = P(*(lead + (None,) * (ids.ndim - 1)))
     out_spec = P(*(lead + (None,) * ids.ndim))
 
-    fn = runtime.shard_map(
+    fn = jax.shard_map(
         lambda t, i: _local_lookup(t, i, rows_per_shard),
         mesh=mesh,
         in_specs=(P(SHARD_AXIS, None), id_spec),
@@ -97,10 +97,10 @@ def _row_update_fn(mesh, rows_per_shard: int):
         safe = jnp.where(ok, local_ids, rows_per_shard)
         return t.at[safe].set(r, mode="drop")
 
-    fn = runtime.shard_map(local, mesh=mesh,
-                           in_specs=(P(SHARD_AXIS, None), P(None),
-                                     P(None, None)),
-                           out_specs=P(SHARD_AXIS, None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(SHARD_AXIS, None), P(None),
+                                 P(None, None)),
+                       out_specs=P(SHARD_AXIS, None), check_vma=False)
     return jax.jit(fn, donate_argnums=_donate_argnums())
 
 
@@ -231,7 +231,7 @@ def sharded_gather_a2a(table: jax.Array, ids: jax.Array,
                                n_loc)].add(recv.reshape(-1, D))
         return out[:n_loc]
 
-    fn = runtime.shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(BIG_AXES, None), P(BIG_AXES)),
                        out_specs=P(BIG_AXES, None), check_vma=False)
     out = fn(table, ids)
@@ -329,7 +329,7 @@ def sharded_embedding_bag_2d(table: jax.Array, ids: jax.Array,
         weights = jnp.ones(ids.shape, jnp.float32)
     id_spec = P(batch_axes, None) if scatterable else P(None, None)
     out_spec = P(batch_axes, None) if scatterable else P(None, None)
-    fn = runtime.shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(BIG_AXES, None), id_spec, id_spec),
                        out_specs=out_spec, check_vma=False)
     return fn(table, ids, weights)
