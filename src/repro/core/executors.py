@@ -27,6 +27,7 @@ from typing import Any, Callable, Optional
 
 from repro.core.sedp import Event, Plan, StageProcessor
 from repro.obs.metrics import Histogram
+from repro.obs.trace import StallMonitor
 from repro.serve.batcher import MicroBatcher
 
 log = logging.getLogger(__name__)
@@ -78,6 +79,9 @@ class RunReport:
     # accounting path when ``exact_latencies=False`` drops the raw list
     # (bounded memory on long-running serving loops)
     latency_hist: Optional[Histogram] = None
+    # spans of the whole process, no request's: the stall monitor's
+    # ``process:stall`` spans of a traced AsyncExecutor run
+    process_spans: list = field(default_factory=list)
 
     @property
     def throughput(self):
@@ -189,6 +193,18 @@ class AsyncExecutor:
         return self.channels[stage].qsize()
 
     def _worker(self, sp: StageProcessor, gen: int):
+        """A stage worker. In a traced run it holds a tracer slot (its open
+        stage and phase), which the op's phases and the stall monitor
+        read."""
+        if self.tracer is None:
+            return self._serve(sp, gen)
+        self.tracer.bind_thread()
+        try:
+            self._serve(sp, gen)
+        finally:
+            self.tracer.unbind_thread()
+
+    def _serve(self, sp: StageProcessor, gen: int):
         ch = self.channels[sp.name]
         wait_s = (sp.max_wait_s if sp.max_wait_s is not None
                   else self.batch_timeout_s)
@@ -329,9 +345,12 @@ class AsyncExecutor:
         gen = self._gen
         self._stop.clear()
         self.stats = defaultdict(StageStats)
+        monitor = (StallMonitor(self.tracer).start()
+                   if self.tracer is not None else None)
         for sp in self.plan.stages.values():
-            for _ in range(sp.parallelism):
+            for k in range(sp.parallelism):
                 th = threading.Thread(target=self._worker, args=(sp, gen),
+                                      name=f"sedp:{sp.name}:{k}",
                                       daemon=True)
                 th.start()
                 self._threads.append(th)
@@ -360,6 +379,8 @@ class AsyncExecutor:
         for th in self._threads:        # workers exit within their poll tick
             th.join(timeout=2.0)
         self._threads = [th for th in self._threads if th.is_alive()]
+        if monitor is not None:
+            monitor.stop()
         hist = Histogram("latency_s", "end-to-end request latency")
         for ev in done:
             hist.observe(ev.done_at - ev.born_at)
@@ -371,7 +392,8 @@ class AsyncExecutor:
             results=done, offered=len(events), completed=len(done),
             latency_hist=hist,
             expired=sum(st.expired for st in self.stats.values()),
-            errors=sum(st.errors for st in self.stats.values()))
+            errors=sum(st.errors for st in self.stats.values()),
+            process_spans=monitor.spans if monitor is not None else [])
         return rep
 
 

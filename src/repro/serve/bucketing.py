@@ -23,6 +23,7 @@ Three pieces:
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -96,10 +97,18 @@ def compact_history(hist_ids: np.ndarray,
     return out
 
 
+_NULL = contextlib.nullcontext()
+
+
+def _untimed(name: str):
+    return _NULL
+
+
 def bucketed_candidate_rerank(score_fn, params, hist_ids, user_fields,
                               cands, cand_buckets: ShapeBucketer,
                               hist_buckets: ShapeBucketer,
-                              item_fields=(), keep: int = 12) -> list:
+                              item_fields=(), keep: int = 12,
+                              phase: Optional[Callable] = None) -> list:
     """One request's candidate set through a fused shared-history scorer,
     every varying dimension padded to a bucket.
 
@@ -116,26 +125,34 @@ def bucketed_candidate_rerank(score_fn, params, hist_ids, user_fields,
     on the probability scale (sigmoid of the ranking logits — the same
     scale ``serve_scores`` puts in ``payload["score"]``; for retrieval
     similarities the sigmoid is monotone, so the ranking is unchanged).
+    ``phase(name)``, where given, returns a context manager that times
+    each step: ``pack`` (the device inputs), ``launch`` (the jitted call
+    until it returns), ``wait`` (blocking on its results) and ``post``.
     """
     import jax.numpy as jnp
-    C = len(cands)
-    Cp = cand_buckets.fit(C)
-    ids = np.fromiter((c[0] for c in cands), np.int64, C)
-    ids_p = np.concatenate([ids, np.full(Cp - C, ids[0])])
-    user = {"fields": {k: jnp.asarray(np.asarray(v))[None]
-                       for k, v in user_fields.items()}}
-    if hist_ids is not None:
-        hist = compact_history(np.asarray(hist_ids), hist_buckets)
-        user["hist"] = jnp.asarray(hist)[None]
-    cand_ids = {"item_id": jnp.asarray(ids_p)}
-    for name, bag in item_fields:
-        shape = (Cp,) if bag == 1 else (Cp, bag)
-        cand_ids[name] = jnp.zeros(shape, jnp.int32)
-    v, i = score_fn(params, user, cand_ids)
-    v, i = np.asarray(v, np.float64), np.asarray(i)
-    probs = 1.0 / (1.0 + np.exp(-v))            # monotone: ranking unchanged
-    return [(int(ids_p[j]), float(s))
-            for s, j in zip(probs, i) if j < C][:keep]
+    phase = phase or _untimed
+    with phase("pack"):
+        C = len(cands)
+        Cp = cand_buckets.fit(C)
+        ids = np.fromiter((c[0] for c in cands), np.int64, C)
+        ids_p = np.concatenate([ids, np.full(Cp - C, ids[0])])
+        user = {"fields": {k: jnp.asarray(np.asarray(v))[None]
+                           for k, v in user_fields.items()}}
+        if hist_ids is not None:
+            hist = compact_history(np.asarray(hist_ids), hist_buckets)
+            user["hist"] = jnp.asarray(hist)[None]
+        cand_ids = {"item_id": jnp.asarray(ids_p)}
+        for name, bag in item_fields:
+            shape = (Cp,) if bag == 1 else (Cp, bag)
+            cand_ids[name] = jnp.zeros(shape, jnp.int32)
+    with phase("launch"):
+        v, i = score_fn(params, user, cand_ids)
+    with phase("wait"):
+        v, i = np.asarray(v, np.float64), np.asarray(i)
+    with phase("post"):
+        probs = 1.0 / (1.0 + np.exp(-v))        # monotone: ranking unchanged
+        return [(int(ids_p[j]), float(s))
+                for s, j in zip(probs, i) if j < C][:keep]
 
 
 @dataclass

@@ -591,9 +591,11 @@ class ScenarioRuntime:
                 np.stack([p["hist"] for p in payloads]))
         return batch
 
-    def rerank_candidates(self, params, payload, keep: int = 12):
+    def rerank_candidates(self, params, payload, keep: int = 12,
+                          phase: Optional[Callable] = None):
         """Full re-rank of the request's surviving candidate set through
-        the fused shared-history scorer, every dimension bucketed."""
+        the fused shared-history scorer, every dimension bucketed
+        (``phase``: see ``bucketed_candidate_rerank``)."""
         mc = self.model_cfg
         cands = payload.get("candidates")
         if not cands or self.rerank is None or not mc.seq_len:
@@ -603,7 +605,7 @@ class ScenarioRuntime:
             {f.name: payload["user_fields"][f.name] for f in mc.user_fields},
             cands, self.cand_buckets, self.hist_buckets,
             item_fields=[(f.name, f.bag) for f in mc.item_fields
-                         if f.name != "item_id"], keep=keep)
+                         if f.name != "item_id"], keep=keep, phase=phase)
 
     def retrieve_candidates(self, params, payload, keep: int = 12) -> list:
         """One query against the candidate set through the scenario's

@@ -19,6 +19,7 @@ stage class serves DIN, DIEN and retrieval scenarios alike.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -26,7 +27,8 @@ from typing import Optional
 
 import numpy as np
 
-from repro.obs.trace import add_child_spans, annotate, shard_fanout_spans
+from repro.obs.trace import (add_child_spans, annotate, phase,
+                             shard_fanout_spans)
 from repro.core.cube import (TIER_DEFAULT, TIER_PRIMARY, TIER_REPLICA,
                              TIER_STALE_CACHE)
 from repro.sparse.hashing import hash_bucket_np
@@ -521,36 +523,46 @@ class RerankStage(Stage):
         params = gen.payload
         B = len(batch)
         payloads = [ev.payload for ev in batch]
-        # pad to the covering batch bucket (bounded jit-trace count);
-        # scores are per-row, so slicing [:B] discards the filler exactly
-        padded = rt.batch_buckets.pad_rows(payloads)
-        b = rt.pack_batch(padded)
-        scores = np.asarray(rt.serve(params, b))[:B]
+        # the op's phases (obs.trace.phase): pack, launch, wait, post, for
+        # the pointwise batch and for each request's candidate set
+        with phase(batch, "pack", call="pointwise"):
+            # pad to the covering batch bucket (bounded jit-trace count);
+            # scores are per-row, so slicing [:B] discards the filler
+            padded = rt.batch_buckets.pad_rows(payloads)
+            b = rt.pack_batch(padded)
+        with phase(batch, "launch", call="pointwise"):
+            scores = rt.serve(params, b)
+        with phase(batch, "wait", call="pointwise"):
+            scores = np.asarray(scores)[:B]
         now = ctx.now() if ctx is not None else 0.0
         for ev, s in zip(batch, scores):
             ev.payload["score"] = float(s)
             ev.payload["generation"] = gen.stamp
             annotate(ev, batch_bucket=len(padded), generation=gen.stamp)
-            rt.rerank_candidates(params, ev.payload, keep=self.keep)
-        sub.query_cache.put_many(
-            [rt.user_key(ev.payload) for ev in batch],
-            [ev.payload["item_id"] for ev in batch],
-            [float(s) for s in scores], now, version=qv)
-        # delta-side cache-aside guard (the query-cache twin of the cube
-        # stage's): these scores embed cube rows fetched at the events'
-        # pinned versions — if a delta published since, its
-        # invalidate_items may have run BEFORE our insert, resurrecting a
-        # stale score. Drop exactly the batch items deltas touched since
-        # the earliest pin; a cold touched-key log forces the drop.
-        vmin = min((ev.payload.get("cube_version", 0) for ev in batch),
-                   default=0)
-        if sub.cube.version != vmin:
-            items = {ev.payload["item_id"] for ev in batch}
-            touched = sub.updates.touched_since(vmin)
-            if touched is not None:
-                items &= touched[1]
-            if items:
-                sub.query_cache.invalidate_items(items)
+            rt.rerank_candidates(params, ev.payload, keep=self.keep,
+                                 phase=functools.partial(
+                                     phase, [ev], call="candidates"))
+        with phase(batch, "post", call="pointwise"):
+            sub.query_cache.put_many(
+                [rt.user_key(ev.payload) for ev in batch],
+                [ev.payload["item_id"] for ev in batch],
+                [float(s) for s in scores], now, version=qv)
+            # delta-side cache-aside guard (the query-cache twin of the
+            # cube stage's): these scores embed cube rows fetched at the
+            # events' pinned versions — if a delta published since, its
+            # invalidate_items may have run BEFORE our insert,
+            # resurrecting a stale score. Drop exactly the batch items
+            # deltas touched since the earliest pin; a cold touched-key
+            # log forces the drop.
+            vmin = min((ev.payload.get("cube_version", 0) for ev in batch),
+                       default=0)
+            if sub.cube.version != vmin:
+                items = {ev.payload["item_id"] for ev in batch}
+                touched = sub.updates.touched_since(vmin)
+                if touched is not None:
+                    items &= touched[1]
+                if items:
+                    sub.query_cache.invalidate_items(items)
         return batch
 
 
